@@ -5,9 +5,13 @@ Paper protocol: 2500 uniformly sampled design points evaluated with the
 ADRS against that pool's true Pareto front, repeated over seeds. Pool
 metrics are cached under results/bench_cache/ — evaluation is one batched
 XLA call, but the cache keeps repeated figure runs identical and instant.
+A cache file is keyed by workload, pool size, seed AND a digest of the
+evaluation code, so an edited cost model misses instead of serving stale
+metrics.
 """
 from __future__ import annotations
 
+import hashlib
 import os
 from dataclasses import dataclass
 
@@ -21,6 +25,21 @@ CACHE_DIR = os.path.join(os.path.dirname(__file__), "..", "results",
                          "bench_cache")
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "results",
                        "benchmarks")
+
+#: the code a cached pool's metrics depend on (paths under src/repro/)
+_EVAL_SOURCES = ("soc/model.py", "soc/simplified.py", "soc/workloads.py",
+                 "core/space.py")
+
+
+def _eval_code_digest() -> str:
+    import repro
+
+    h = hashlib.sha256()
+    for rel in _EVAL_SOURCES:
+        with open(os.path.join(list(repro.__path__)[0], rel), "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()[:12]
+
 
 METHODS = ("soc-tuner", "microal", "regression", "xgb", "rf", "svr", "random")
 
@@ -53,7 +72,8 @@ def make_bench(workload: str = "resnet50", n_pool: int = 2500,
         return Bench(space=space, pool=pool, y=None, ref_front=None,
                      flow_factory=lambda: flow_cls(space, workload),
                      workload=workload, simplified=simplified)
-    tag = f"{workload}_{n_pool}_{seed}{'_simp' if simplified else ''}"
+    tag = (f"{workload}_{n_pool}_{seed}{'_simp' if simplified else ''}"
+           f"_{_eval_code_digest()}")
     cache = os.path.join(CACHE_DIR, tag + ".npz")
     if os.path.exists(cache):
         z = np.load(cache)

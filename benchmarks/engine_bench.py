@@ -117,7 +117,10 @@ def pool_point(a) -> dict:
 
 
 def _run_pool_subprocess(a, n_pool: int) -> dict:
-    """Run one sweep point isolated so its peak RSS is its own."""
+    """Run one sweep point isolated so its peak RSS is its own.
+
+    The sweeping parent never starts a JAX backend (it only launches and
+    reads children), so each child can open the accelerator in turn."""
     with tempfile.NamedTemporaryFile(suffix=".json", delete=False) as f:
         tmp = f.name
     cmd = [sys.executable, "-m", "benchmarks.engine_bench",
@@ -386,4 +389,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

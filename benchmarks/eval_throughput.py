@@ -47,6 +47,9 @@ def main(n: int = 2500, workload: str = "resnet50", verbose: bool = True):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=2500)
     ap.add_argument("--workload", default="resnet50")

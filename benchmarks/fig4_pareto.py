@@ -77,6 +77,9 @@ def simplified_gap(T: int = 20, b: int = 20, n: int = 30,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--T", type=int, default=20)
     ap.add_argument("--b", type=int, default=20)

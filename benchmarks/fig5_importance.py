@@ -72,6 +72,9 @@ def main(n: int = 30, v_th: float = 0.07, workload: str = "resnet50",
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=30)
     ap.add_argument("--v-th", type=float, default=0.07)

@@ -81,6 +81,9 @@ def breakdown(workload: str = "resnet50", T: int = 20, b: int = 20,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--T", type=int, default=20)
     ap.add_argument("--b", type=int, default=20)

@@ -108,6 +108,9 @@ def main(workloads=("resnet50", "mobilenet", "transformer"), seeds: int = 2,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--workloads", default="resnet50,mobilenet,transformer",
                     help="comma-separated workload names (see repro.soc)")

@@ -69,7 +69,9 @@ def evaluate(arch: str, shape: str, mesh: str, pt: dict, out_dir: str) -> dict:
     cmd = [sys.executable, "-m", "repro.launch.dryrun", "--arch", arch,
            "--shape", shape, "--mesh", mesh, "--out", out_dir,
            "--overrides", json.dumps(to_overrides(pt))]
-    env = dict(os.environ, PYTHONPATH="src")
+    # the dry run compiles for 512 faked host devices; this process may
+    # already hold an accelerator, so the child stays on the CPU
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     res = subprocess.run(cmd, capture_output=True, text=True, env=env)
     line = res.stdout.strip().splitlines()[-1] if res.stdout.strip() else "{}"
     rec = json.loads(line)
@@ -146,4 +148,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -94,7 +94,6 @@ from typing import NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
 from repro.kernels.backend import (auto_chunk, resolve_round_backend,
@@ -102,7 +101,7 @@ from repro.kernels.backend import (auto_chunk, resolve_round_backend,
 
 from .acquisition import imoo_scores, imoo_scores_batch, mes_information_gain
 from .gp import (JITTER, PAD_BUCKET, GPParams, _default_params, _fit, _kernel,
-                 _standardize, fit_gp, fit_gp_batch, pad_training)
+                 _mm, _standardize, fit_gp, fit_gp_batch, pad_training)
 
 __all__ = ["BOEngine", "BatchedBOEngine", "EngineStats", "FANTASY_MODES",
            "PROFILE_STAGES"]
@@ -254,7 +253,7 @@ def _chol_block(params_ref: GPParams, L, x, mask, s0: int):
                + jnp.diag(1e6 * mask[s0:]))
         L11 = Li[:s0, :s0]
         L21 = jax.scipy.linalg.solve_triangular(L11, K12, lower=True).T
-        L22 = jnp.linalg.cholesky(K22 - L21 @ L21.T)
+        L22 = jnp.linalg.cholesky(K22 - _mm(L21, L21.T))
         return Li.at[s0:, :s0].set(L21).at[s0:, s0:].set(L22)
 
     return jax.vmap(one)(params_ref.log_ls, params_ref.log_var,
@@ -279,7 +278,7 @@ def _v_chunk_block(params_ref: GPParams, L, Vc, x, pc, s0: int):
                       differentiable=False)                       # [B, C]
         L21, L22 = Li[s0:, :s0], Li[s0:, s0:]
         Vb = jax.scipy.linalg.solve_triangular(
-            L22, Ksb - L21 @ Vi[:s0], lower=True)
+            L22, Ksb - _mm(L21, Vi[:s0]), lower=True)
         return Vi.at[s0:].set(Vb)
 
     return jax.vmap(one)(params_ref.log_ls, params_ref.log_var, L, Vc)
@@ -330,11 +329,11 @@ def _frontier_ystar(params_ref: GPParams, L, beta, x, xq, y_mean, y_std, key,
         Vs = jax.scipy.linalg.solve_triangular(Li, Ks, lower=True)
         mean_q, _ = _col_moments(log_var, bi, Vs)
         Kqq = _kernel((log_ls, log_var), xq, xq, differentiable=False)
-        cov = Kqq - Vs.T @ Vs
+        cov = Kqq - _mm(Vs.T, Vs)
         jit_ = 1e-4 * jnp.exp(log_var) + 1e-6
         Lq = jnp.linalg.cholesky(cov + jit_ * jnp.eye(q))
         eps = jax.random.normal(k, (q, s))
-        return mean_q[:, None] + Lq @ eps                             # [q, s]
+        return mean_q[:, None] + _mm(Lq, eps)                         # [q, s]
 
     keys = jax.random.split(key, m)
     samp = jax.vmap(one)(params_ref.log_ls, params_ref.log_var, L, beta, keys)
@@ -1721,9 +1720,9 @@ class BatchedBOEngine(_EngineBase):
         fn = self._shard_cache.get(key)
         if fn is None:
             spec = PartitionSpec(self.mesh_axis)
-            fn = jax.jit(shard_map(
+            fn = jax.jit(jax.shard_map(
                 functools.partial(impl, **statics), mesh=self.mesh,
-                in_specs=spec, out_specs=spec, check_rep=False))
+                in_specs=spec, out_specs=spec, check_vma=False))
             self._shard_cache[key] = fn
         return fn(*args)
 
@@ -1957,15 +1956,11 @@ class BatchedBOEngine(_EngineBase):
         # scenarios whose drift exceeds the tolerance refactor, the rest
         # block-update. An all-or-nothing fleet takes the identical single
         # dispatch as before (the golden-pinned path); a mixed fleet runs
-        # one gathered dispatch per group and scatters the results back.
-        # Under a mesh the fleet-wide decision is kept: gathered sub-fleets
-        # would break the scenario axis's even device split.
+        # one dispatch per group and scatters the results back.
         if first or grew or s0 <= 0:
             ref_idx = np.arange(self.S)
         else:
             ref_idx = np.where(np.asarray(drift) > self.drift_tol)[0]
-            if self.mesh is not None and ref_idx.size:
-                ref_idx = np.arange(self.S)
         upd_idx = np.setdiff1d(np.arange(self.S), ref_idx)
         if upd_idx.size == 0:
             L, V, picks, ystar = self._dispatch(
@@ -2019,20 +2014,34 @@ class BatchedBOEngine(_EngineBase):
         fleet, just over a gathered sub-fleet (the donated L/V are gathered
         copies, so the live state survives an interrupt). ``params_ref``
         mixes per scenario: refactoring scenarios adopt their fresh fit,
-        the others keep their reference snapshot."""
+        the others keep their reference snapshot.
+
+        Under a mesh a gathered sub-fleet would break the scenario axis's
+        even device split, so both programs run over the whole sharded
+        fleet and each scenario keeps its own group's result — the same
+        per-scenario values at twice the round's device work."""
         ri, ui = jnp.asarray(ref_idx), jnp.asarray(upd_idx)
         evalm = self._evalm_chunks()
         take = lambda tree, i: jax.tree.map(lambda a: a[i], tree)
-        L_r, V_r, picks_r, ystar_r = _refactor_select_batch(
-            take(params, ri), x[ri], mask[ri], self._pool_c[ri],
-            self._base[ri], yn[ri], y_mean[ri], y_std[ri], sub[ri],
-            evalm[ri], keys[ri], weights[ri],
-            s=self.s_frontiers, select=do_select, fused=fused)
-        L_u, V_u, picks_u, ystar_u = _update_select_batch(
-            take(state.params_ref, ui), state.L[ui], state.V[ui], x[ui],
-            mask[ui], self._pool_c[ui], self._base[ui], yn[ui], y_mean[ui],
-            y_std[ui], sub[ui], evalm[ui], keys[ui], weights[ui],
-            s=self.s_frontiers, s0=s0, select=do_select, fused=fused)
+        ref_args = (params, x, mask, self._pool_c, self._base, yn, y_mean,
+                    y_std, sub, evalm, keys, weights)
+        upd_args = (state.params_ref, state.L, state.V, x, mask,
+                    self._pool_c, self._base, yn, y_mean, y_std, sub, evalm,
+                    keys, weights)
+        ref_st = {"s": self.s_frontiers, "select": do_select, "fused": fused}
+        upd_st = dict(ref_st, s0=s0)
+        if self.mesh is None:
+            L_r, V_r, picks_r, ystar_r = _refactor_select_batch(
+                *take(ref_args, ri), **ref_st)
+            L_u, V_u, picks_u, ystar_u = _update_select_batch(
+                *take(upd_args, ui), **upd_st)
+        else:
+            L_r, V_r, picks_r, ystar_r = take(self._dispatch(
+                "refactor_select", _refactor_select_batch_impl,
+                _refactor_select_batch, ref_st, *ref_args), ri)
+            L_u, V_u, picks_u, ystar_u = take(self._dispatch(
+                "update_select", _update_select_batch_impl,
+                _update_select_batch, upd_st, *upd_args), ui)
         L = state.L.at[ri].set(L_r).at[ui].set(L_u)
         V = state.V.at[ri].set(V_r).at[ui].set(V_u)
         ystar = jnp.zeros((self.S,) + ystar_r.shape[1:], ystar_r.dtype)

@@ -28,6 +28,12 @@ JITTER = 1e-5
 PAD_BUCKET = 8
 
 
+def _mm(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """Full-f32 matmul. A TPU's default f32 matmul rounds its operands to
+    bfloat16, which breaks the Cholesky/Schur-complement algebra here."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 class GPParams(NamedTuple):
     log_ls: jnp.ndarray  # [m, d] ARD log-lengthscales
     log_var: jnp.ndarray  # [m] log signal variance
@@ -71,7 +77,7 @@ def _nll_one(log_ls, log_var, log_noise, x, y, mask=None) -> jnp.ndarray:
         K = K + jnp.diag(1e6 * mask)
     L = jnp.linalg.cholesky(K)
     alpha = jax.scipy.linalg.cho_solve((L, True), y)
-    nll = 0.5 * y @ alpha + jnp.sum(jnp.log(jnp.diagonal(L))) + 0.5 * n * jnp.log(2 * jnp.pi)
+    nll = _mm(0.5 * y, alpha) + jnp.sum(jnp.log(jnp.diagonal(L))) + 0.5 * n * jnp.log(2 * jnp.pi)
     # Weak log-normal hyperpriors keep lengthscales in a sane band when n is
     # tiny (first BO rounds) — standard practice, removable via prior_w=0.
     prior = 0.05 * (jnp.sum(log_ls**2) + log_var**2 + (log_noise + 4.0) ** 2)
@@ -235,7 +241,7 @@ def gp_predict(state: GPState, xq: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarra
     def one(log_ls, log_var, L, alpha):
         Ks = _kernel((log_ls, log_var), state.x, xq,
                      differentiable=False)  # [n, q]
-        mean = Ks.T @ alpha
+        mean = _mm(Ks.T, alpha)
         Vs = jax.scipy.linalg.solve_triangular(L, Ks, lower=True)
         var = jnp.exp(log_var) - jnp.sum(Vs * Vs, axis=0)
         return mean, jnp.sqrt(jnp.maximum(var, 1e-10))
@@ -260,16 +266,16 @@ def gp_joint_samples(state: GPState, xq: jnp.ndarray, key: jax.Array,
         Ks = _kernel((log_ls, log_var), state.x, xq,
                      differentiable=False)  # [n, q]
         Kqq = _kernel((log_ls, log_var), xq, xq, differentiable=False)
-        mean = Ks.T @ alpha
+        mean = _mm(Ks.T, alpha)
         Vs = jax.scipy.linalg.solve_triangular(L, Ks, lower=True)
-        cov = Kqq - Vs.T @ Vs
+        cov = Kqq - _mm(Vs.T, Vs)
         # prior-scaled jitter: the f32 subtraction leaves small negative
         # eigenvalues when the posterior collapses (long lengthscales);
         # 1e-4 x prior variance dominates them at every hyperparameter
         jit = 1e-4 * jnp.exp(log_var) + 1e-6
         Lq = jnp.linalg.cholesky(cov + jit * jnp.eye(q))
         eps = jax.random.normal(k, (q, s))
-        return mean[:, None] + Lq @ eps  # [q, s]
+        return mean[:, None] + _mm(Lq, eps)  # [q, s]
 
     keys = jax.random.split(key, state.y.shape[1])
     samp = jax.vmap(one)(state.params.log_ls, state.params.log_var,

@@ -1,4 +1,7 @@
-"""Pallas TPU kernels (validated with interpret=True off-TPU).
+"""Pallas TPU kernels: interpret mode off-TPU, compiled by Mosaic on a TPU.
+
+The four main-path kernels compile for v5e (pinned by
+``tests/test_tpu_compile.py``); ``flash_attn`` belongs to the LM stack.
 
 - ``backend``       unified dispatch (XLA fidelity default; Pallas-on-TPU via
                     ``REPRO_PAIRDIST_BACKEND=platform`` or forced) with

@@ -89,10 +89,15 @@ def resolve_backend(backend: str = "auto", n: int | None = None,
 
 
 def sqdist_xla(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """‖a_i − b_j‖² via the MXU-friendly ‖a‖²+‖b‖²−2ab form (pure XLA)."""
+    """‖a_i − b_j‖² via the MXU-friendly ‖a‖²+‖b‖²−2ab form (pure XLA).
+
+    The cross term is a full-f32 matmul: a TPU's default f32 matmul rounds
+    its operands to bfloat16, and the cancellation in this form then
+    leaves kernel matrices too far from PSD for a Cholesky."""
     aa = jnp.sum(a * a, axis=-1)
     bb = jnp.sum(b * b, axis=-1)
-    return jnp.maximum(aa[:, None] + bb[None, :] - 2.0 * (a @ b.T), 0.0)
+    cross = jnp.matmul(a, b.T, precision=jax.lax.Precision.HIGHEST)
+    return jnp.maximum(aa[:, None] + bb[None, :] - 2.0 * cross, 0.0)
 
 
 def rbf_xla(a: jnp.ndarray, b: jnp.ndarray, bandwidth: float) -> jnp.ndarray:

@@ -1,8 +1,11 @@
 """Shared kernel plumbing: interpret-mode fallback + padding helpers.
 
-TPU is the *target*; this container is CPU-only, so every ``ops.py`` wrapper
-runs the kernel with ``interpret=True`` off-TPU (the kernel body executes in
-Python with real BlockSpec tiling semantics) and compiled on real hardware.
+TPU is the *target*: every ``ops.py`` wrapper runs its kernel with
+``interpret=True`` off-TPU (the kernel body executes with real BlockSpec
+tiling semantics) and compiled by Mosaic on a TPU. ``pairdist``,
+``pareto_count``, ``systolic_eval`` and ``round_fused`` compile for a v5e
+chip at the main path's shapes; ``tests/test_tpu_compile.py`` pins that
+with ahead-of-time compiles for a described v5e topology.
 """
 from __future__ import annotations
 

@@ -27,8 +27,9 @@ def _body(xi_ref, xj_ref, out_ref, *, inv2s2: float, fuse_rbf: bool):
     xj = xj_ref[...].astype(jnp.float32)           # [TJ, D]
     ii = jnp.sum(xi * xi, axis=-1)[:, None]        # [TI, 1]
     jj = jnp.sum(xj * xj, axis=-1)[None, :]        # [1, TJ]
-    cross = jax.lax.dot_general(                   # MXU: [TI, TJ]
-        xi, xj, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    cross = jax.lax.dot_general(                   # MXU: [TI, TJ], full f32
+        xi, xj, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
     d2 = jnp.maximum(ii + jj - 2.0 * cross, 0.0)
     out_ref[...] = jnp.exp(-d2 * inv2s2) if fuse_rbf else d2
 

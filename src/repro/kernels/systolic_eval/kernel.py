@@ -22,12 +22,14 @@ from jax.experimental import pallas as pl
 TILE_N = 128
 
 
-def _body(vals_ref, layers_ref, out_ref):
-    from repro.soc.model import _metrics_tile
+def _body(vals_ref, lrows_ref, out_ref):
+    from repro.soc.model import _metrics_cols
 
     vals = vals_ref[...].astype(jnp.float32)     # [TN, 26]
-    layers = layers_ref[...].astype(jnp.float32)  # [L, 5]
-    out_ref[...] = _metrics_tile(vals, layers)    # [TN, 3]
+    lrows = lrows_ref[...].astype(jnp.float32)   # [5, L]
+    # each layer column as a [1, L] row slice: Mosaic cannot relayout a
+    # lane-strided column of the [L, 5] table into a row
+    out_ref[...] = _metrics_cols(vals, tuple(lrows[i:i + 1] for i in range(5)))
 
 
 def soc_metrics(vals: jnp.ndarray, layers: jnp.ndarray, *,
@@ -41,9 +43,9 @@ def soc_metrics(vals: jnp.ndarray, layers: jnp.ndarray, *,
         grid=grid,
         in_specs=[
             pl.BlockSpec((TILE_N, F), lambda i: (i, 0)),
-            pl.BlockSpec((L, 5), lambda i: (0, 0)),
+            pl.BlockSpec((5, L), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((TILE_N, 3), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, 3), jnp.float32),
         interpret=interpret,
-    )(vals, layers)
+    )(vals, layers.T)

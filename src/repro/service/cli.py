@@ -55,6 +55,8 @@ import sys
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
+
 __all__ = ["main", "build_parser", "build_fleet_parser",
            "build_serve_parser", "build_client_parser",
            "build_cache_gc_parser"]
@@ -305,6 +307,7 @@ def build_cache_gc_parser() -> argparse.ArgumentParser:
 
 def main_fleet(argv=None) -> int:
     a = build_fleet_parser().parse_args(argv)
+    enable_compile_cache()
     from repro.core import FleetScenario, make_space
     from repro.soc import DelayedFlow, VLSIFlow
     from .fleet_runner import fleet_service
@@ -361,6 +364,7 @@ def main_fleet(argv=None) -> int:
 
 def main_serve(argv=None) -> int:
     a = build_serve_parser().parse_args(argv)
+    enable_compile_cache()
     from repro.core import make_space
     from repro.soc import DelayedFlow, VLSIFlow
     from .jobs import JobSpec
@@ -481,6 +485,7 @@ def main(argv=None) -> int:
     if argv and argv[0] == "run":
         argv = argv[1:]
     a = build_parser().parse_args(argv)
+    enable_compile_cache()
     from repro.core import make_space
     from repro.soc import DelayedFlow, VLSIFlow
     from .runner import service_tuner

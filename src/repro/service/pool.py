@@ -2,7 +2,9 @@
 
 A :class:`FlowPool` owns a set of workers (a ``spawn`` process pool by
 default — the VLSI flow is CPU-hours of work per design point, and ``fork``
-under a live JAX runtime is unsafe — or threads, an inline synchronous
+under a live JAX runtime is unsafe; each process worker evaluates on the
+host CPU backend, because an accelerator belongs to the one parent process
+that drives the engine — or threads, an inline synchronous
 executor for tests, or any user-supplied ``concurrent.futures.Executor``)
 and a ticket queue. ``submit(row, idx_row)`` dispatches ONE design point and
 returns a monotonically increasing ticket; ``drain(min_done)`` blocks until
@@ -30,6 +32,7 @@ from __future__ import annotations
 
 import concurrent.futures as cf
 import multiprocessing
+import os
 import time
 from typing import Callable
 
@@ -40,6 +43,19 @@ from repro.obs import MetricsRegistry
 from .flowcache import FlowDiskCache
 
 __all__ = ["FlowPool", "InlineExecutor"]
+
+
+def _pin_worker_to_cpu() -> None:
+    """Process-worker initializer: pin the worker's JAX to the host CPU.
+
+    The parent holds the accelerator (a TPU chip admits one process), so a
+    worker that opened it would fail on the libtpu lock or hang. The
+    executor calls this before the worker unpickles its first task, hence
+    before any JAX backend starts in it."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
 
 
 def _flow_task(flow, idx_row: np.ndarray) -> np.ndarray:
@@ -109,7 +125,8 @@ class FlowPool:
         if executor == "process":
             self._ex = cf.ProcessPoolExecutor(
                 max_workers=max_workers,
-                mp_context=multiprocessing.get_context(mp_context))
+                mp_context=multiprocessing.get_context(mp_context),
+                initializer=_pin_worker_to_cpu)
         elif executor == "thread":
             self._ex = cf.ThreadPoolExecutor(max_workers=max_workers)
         elif executor == "inline":

@@ -11,7 +11,8 @@
 
 All runners are **pool-safe**: picklable (device arrays are rebuilt on
 unpickle, not shipped), so ``repro.service.FlowPool`` can dispatch them to
-spawn-context worker processes.
+spawn-context worker processes. Those workers are pinned to the host CPU
+backend, so a flow unpickled there evaluates on the CPU.
 """
 from __future__ import annotations
 

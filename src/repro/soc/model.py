@@ -108,12 +108,22 @@ def _select(core_idx: jnp.ndarray, table: tuple[float, ...]) -> jnp.ndarray:
     return out
 
 
+def _ceil_div(a, b):
+    """``ceil(a / b)`` for integer-valued ``a, b > 0``, exact even where the
+    f32 divide is not correctly rounded (a TPU's is not): a quotient of
+    exact multiples that lands an ulp above the integer would otherwise
+    ceil one tile too far. Where the divide is exact the two corrections
+    never fire, so the value is ``jnp.ceil(a / b)`` bit for bit."""
+    q = jnp.ceil(a / b)
+    return q - ((q - 1.0) * b >= a) + (q * b < a)
+
+
 def _layer_cost(d: dict[str, jnp.ndarray], M, K, N, reps, kind):
     """Cycles / DRAM bytes / on-chip stream bytes / host commands for one
     (design, layer) pair. All inputs broadcastable; returns dict of scalars."""
     R, C = d["R"], d["C"]
     ib, ob = d["ib"], d["ob"]
-    ceil = lambda a, b: jnp.ceil(a / b)
+    ceil = _ceil_div
 
     is_act_b = (kind == 1.0)  # B operand is an activation (attention)
     # ---------------- WS dataflow ----------------
@@ -139,7 +149,10 @@ def _layer_cost(d: dict[str, jnp.ndarray], M, K, N, reps, kind):
 
     # ---------------- dataflow select ----------------
     df = d["dataflow"]
-    use_os = jnp.where(df == 2.0, compute_os < compute_ws, df == 1.0)
+    # OS if forced (1), or if adaptive (2) and cheaper — written as logic,
+    # not a select between masks, which the TPU kernel compiler refuses
+    use_os = jnp.logical_or(df == 1.0, jnp.logical_and(
+        df == 2.0, compute_os < compute_ws))
     compute = jnp.where(use_os, compute_os, compute_ws)
     dram = jnp.where(use_os, dram_os, dram_ws)
     stream = jnp.where(use_os, stream_os, stream_ws)
@@ -186,23 +199,28 @@ def soc_metrics_multi(vals: jnp.ndarray, layers: jnp.ndarray,
 
 def _metrics_tile(vals: jnp.ndarray, layers: jnp.ndarray,
                   layer_mask: jnp.ndarray | None = None) -> jnp.ndarray:
-    """Un-jitted evaluation body — shared verbatim with the Pallas
-    ``systolic_eval`` kernel (one design tile per grid step), so kernel and
-    oracle cannot drift apart.
+    """Un-jitted evaluation body.
 
     ``layer_mask`` [L] (optional) zeroes out padded layer rows so workloads of
     different depth can be stacked on a common Lmax (``soc_metrics_multi``);
     ``None`` keeps the exact original single-workload computation."""
-    d = decode_design(vals)
-    n = vals.shape[0]
+    return _metrics_cols(vals, tuple(layers[:, i][None, :] for i in range(5)),
+                         layer_mask)
 
-    M, K, N, reps, kind = (layers[:, i] for i in range(5))
+
+def _metrics_cols(vals, cols, layer_mask=None):
+    """``_metrics_tile`` on the layer table's five columns, each given as a
+    ``[1, L]`` row (M, K, N, reps, kind) — shared verbatim with the Pallas
+    ``systolic_eval`` kernel (one design tile per grid step), so kernel and
+    oracle cannot drift apart."""
+    d = decode_design(vals)
+
+    M, K, N, reps, kind = cols
     # Broadcast designs [n,1] against layers [1,L].
     dd = {k: v[:, None] for k, v in d.items()}
-    c = _layer_cost(dd, M[None, :], K[None, :], N[None, :],
-                    reps[None, :], kind[None, :])
+    c = _layer_cost(dd, M, K, N, reps, kind)
     if layer_mask is None:
-        n_layers = layers.shape[0]
+        n_layers = M.shape[1]
     else:
         # Padded rows carry reps=0 so their traffic/MAC terms are already 0;
         # the mask silences the per-layer launch constants below and keeps

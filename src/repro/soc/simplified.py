@@ -9,7 +9,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .model import CONST, decode_design
+from .model import CONST, _ceil_div, decode_design
 
 __all__ = ["simplified_metrics"]
 
@@ -22,7 +22,7 @@ def simplified_metrics(vals: jnp.ndarray, layers: jnp.ndarray) -> jnp.ndarray:
     M, K, N, reps, _ = (layers[:, i] for i in range(5))
     R, C = d["R"][:, None], d["C"][:, None]
     # SCALE-Sim's WS estimate: (2R + C + K - 2) per (M/R x N/C) fold, ideal.
-    folds = jnp.ceil(M[None] / R) * jnp.ceil(N[None] / C)
+    folds = _ceil_div(M[None], R) * _ceil_div(N[None], C)
     cycles = jnp.sum(folds * (2.0 * R + C + K[None] - 2.0) * reps[None], axis=1)
     latency_ms = cycles / CONST["freq_hz"] * 1e3
     macs = jnp.sum(M * K * N * reps)

@@ -170,6 +170,44 @@ def test_sharded_fleet_matches_unsharded_two_devices():
     assert "SHARDED_FLEET_OK" in res.stdout
 
 
+
+def test_sharded_fleet_mixed_drift_rounds_match_unsharded():
+    """A round where only some scenarios drift past ``drift_tol`` refactors
+    those alone and block-updates the rest; under a mesh the same split is
+    made per scenario, so the sharded fleet still reproduces the unsharded
+    trajectory through mixed rounds (2 forced CPU host devices, in a
+    subprocess as above)."""
+    script = textwrap.dedent("""
+        import jax, numpy as np
+        from jax.sharding import Mesh
+        from repro.core import make_space, FleetScenario, fleet_tuner
+        assert jax.device_count() == 2, jax.devices()
+        space = make_space()
+        pool = np.asarray(space.sample(jax.random.PRNGKey(0), 96))
+        scen = [FleetScenario(w, seed=s) for w in ("resnet50", "transformer")
+                for s in (0, 1)]
+        kw = dict(T=4, n=10, b=6, gp_steps=20, incremental=True,
+                  drift_tol=1.0)
+        plain = fleet_tuner(space, pool, scen, **kw)
+        mesh = Mesh(np.asarray(jax.devices()), ("fleet",))
+        sharded = fleet_tuner(space, pool, scen, mesh=mesh, **kw)
+        for res in (plain, sharded):
+            assert res.results[0].engine_stats["mixed_rounds"] >= 1
+        for a, b in zip(plain.results, sharded.results):
+            np.testing.assert_array_equal(a.evaluated_rows, b.evaluated_rows)
+            np.testing.assert_array_equal(a.y, b.y)
+        print("MIXED_SHARDED_FLEET_OK")
+    """)
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=2",
+               PYTHONPATH=os.pathsep.join(
+                   [os.path.join(os.path.dirname(__file__), "..", "src")]
+                   + os.environ.get("PYTHONPATH", "").split(os.pathsep)))
+    res = subprocess.run([sys.executable, "-c", script], env=env,
+                         capture_output=True, text=True, timeout=560)
+    assert res.returncode == 0, res.stdout + res.stderr
+    assert "MIXED_SHARDED_FLEET_OK" in res.stdout
+
 def test_fleet_mesh_validation():
     pools = np.stack([_pool(16), _pool(16)])
     with pytest.raises(ValueError, match="incremental"):

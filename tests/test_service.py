@@ -674,6 +674,50 @@ def test_flow_pool_submit_resolved_keeps_ticket_order(tmp_path):
     pool.close()
 
 
+
+class _WorkerPlatformProbe:
+    """Flow stand-in that reports the evaluating process's JAX platform as
+    three flags: config pinned to cpu, backend is cpu, env says cpu."""
+
+    def __call__(self, idx):
+        import jax
+
+        return np.asarray([[jax.config.jax_platforms == "cpu",
+                            jax.default_backend() == "cpu",
+                            os.environ.get("JAX_PLATFORMS") == "cpu"]],
+                          np.float64)
+
+
+def test_flow_pool_process_workers_pinned_to_cpu(space, small_pool,
+                                                 monkeypatch):
+    """A process worker evaluates on the host CPU backend whatever the
+    caller's environment says (an accelerator belongs to the parent alone),
+    and its flow metrics equal the inline executor's bit for bit."""
+    flow = VLSIFlow(space, "resnet50")
+    rows = [0, 7, 31]
+    inline = FlowPool(flow, workload="resnet50", executor="inline")
+    for r in rows:
+        inline.submit(r, small_pool[r])
+    want = [y for _, _, y in inline.drain(min_done=len(rows))]
+    inline.close()
+    # the spawned worker inherits no platform choice from the caller
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    pool = FlowPool(flow, workload="resnet50", executor="process",
+                    max_workers=1)
+    try:
+        t = pool.submit(-1, np.zeros(1), workload="probe",
+                        flow=_WorkerPlatformProbe())
+        (_, _, flags), = pool.collect([t])
+        np.testing.assert_array_equal(flags, [1.0, 1.0, 1.0])
+        for r in rows:
+            pool.submit(r, small_pool[r])
+        got = [y for _, _, y in pool.drain(min_done=len(rows))]
+    finally:
+        pool.close()
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.tobytes() == w.tobytes()
+
+
 # -------------------------------------------------------------- cache gc
 def _fill_cache(root, n, size=32):
     cache = FlowDiskCache(root)
