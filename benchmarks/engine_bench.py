@@ -32,16 +32,6 @@ the gap, and the symmetric front cross-ADRS into ``BENCH_soak.json``::
 
     PYTHONPATH=src python -m benchmarks.engine_bench \\
         --soak resnet50,mobilenet,transformer --soak-seeds 3 --n-pool 400
-
-**Per-stage round profile**: ``--profile`` runs the incremental engine with
-``profile_stages=True`` — every select round executes as separately-timed
-jitted stages (fit / factor / v_update / frontier / moments / score /
-argmax) — and reports each stage's share of the round total plus the
-sum-vs-total coverage ratio into ``BENCH_engine_profile.json``. Add
-``--trace-dir DIR`` to also dump a ``jax.profiler`` trace of the run::
-
-    PYTHONPATH=src python -m benchmarks.engine_bench --profile \\
-        --n-pool 4096 --T 20
 """
 from __future__ import annotations
 
@@ -65,14 +55,14 @@ POOL_MODE_MIN = 20_000
 
 
 def _run(bench, *, T, n, b, gp_steps, seed, incremental, warm_steps,
-         drift_tol, pool_chunk=None, profile_stages=False):
+         drift_tol, pool_chunk=None):
     flow = bench.flow_factory()
     t0 = time.time()
     res = soc_tuner(bench.space, bench.pool, flow, T=T, n=n, b=b,
                     gp_steps=gp_steps, key=jax.random.PRNGKey(seed),
                     reference_front=bench.ref_front, incremental=incremental,
                     warm_steps=warm_steps, drift_tol=drift_tol,
-                    pool_chunk=pool_chunk, profile_stages=profile_stages)
+                    pool_chunk=pool_chunk)
     wall = time.time() - t0
     # round 0 is setup (ICD + TED init); rounds 1..2 pay jit compiles
     walls = np.asarray([h["wall_s"] for h in res.history[1:]])
@@ -246,52 +236,6 @@ def _soak_main(a) -> None:
           f"mean {s['mean_speedup_wall']:.1f}x wall -> {a.soak_out}")
 
 
-def _profile_main(a) -> None:
-    """Per-stage wall breakdown of the incremental round (profile mode)."""
-    import contextlib
-
-    bench = make_bench(a.workload, n_pool=a.n_pool, seed=a.seed,
-                       with_ref=False)
-    ctx = (jax.profiler.trace(a.trace_dir) if a.trace_dir
-           else contextlib.nullcontext())
-    with ctx:
-        _, rec = _run(bench, T=a.T, n=a.n, b=a.b, gp_steps=a.gp_steps,
-                      seed=a.seed, incremental=True, warm_steps=a.warm_steps,
-                      drift_tol=a.drift_tol, pool_chunk=a.pool_chunk,
-                      profile_stages=True)
-    wall = rec["stage_wall_s"]
-    total = wall["round_total"]
-    stage_sum = sum(v for k, v in wall.items() if k != "round_total")
-    print(f"[engine-bench] profile: n_pool={a.n_pool} T={a.T} "
-          f"({rec['rounds']} rounds, {rec['refactors']} refactors / "
-          f"{rec['block_updates']} updates)")
-    for k, v in wall.items():
-        if k != "round_total":
-            print(f"[engine-bench]   {k:<10} {1e3 * v:9.1f}ms "
-                  f"{100.0 * v / total:5.1f}%")
-    print(f"[engine-bench]   {'sum':<10} {1e3 * stage_sum:9.1f}ms "
-          f"of {1e3 * total:.1f}ms round total "
-          f"({100.0 * stage_sum / total:.1f}% coverage)")
-    out = {
-        "config": {"workload": a.workload, "n_pool": a.n_pool, "T": a.T,
-                   "n": a.n, "b": a.b, "gp_steps": a.gp_steps,
-                   "warm_steps": a.warm_steps, "drift_tol": a.drift_tol,
-                   "pool_chunk": a.pool_chunk, "seed": a.seed,
-                   "backend": jax.default_backend()},
-        "stage_wall_s": wall,
-        "stage_frac": {k: v / total for k, v in wall.items()
-                       if k != "round_total"},
-        "stage_sum_over_total": stage_sum / total,
-        "round_wall_median_s": rec["round_wall_median_s"],
-        "trace_dir": a.trace_dir,
-    }
-    os.makedirs(os.path.dirname(os.path.abspath(a.profile_out)),
-                exist_ok=True)
-    with open(a.profile_out, "w") as f:
-        json.dump(out, f, indent=2)
-    print(f"[engine-bench] -> {a.profile_out}")
-
-
 def main() -> None:
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--workload", default="resnet50")
@@ -323,23 +267,12 @@ def main() -> None:
     p.add_argument("--soak-seeds", type=int, default=3)
     p.add_argument("--soak-out",
                    default=os.path.join(OUT_DIR, "BENCH_soak.json"))
-    p.add_argument("--profile", action="store_true",
-                   help="run the incremental engine with per-stage round "
-                        "timing (profile_stages) and report the breakdown")
-    p.add_argument("--trace-dir", default=None,
-                   help="with --profile: also dump a jax.profiler trace "
-                        "of the run into this directory")
-    p.add_argument("--profile-out",
-                   default=os.path.join(OUT_DIR, "BENCH_engine_profile.json"))
     a = p.parse_args()
     if a.pool_chunk == "none":
         a.pool_chunk = None
     elif a.pool_chunk != "auto":
         a.pool_chunk = int(a.pool_chunk)
 
-    if a.profile:
-        _profile_main(a)
-        return
     if a.soak:
         _soak_main(a)
         return

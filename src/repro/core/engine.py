@@ -88,7 +88,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import NamedTuple, Sequence
 
 import jax
@@ -98,13 +97,13 @@ from jax.sharding import PartitionSpec
 
 from repro.kernels.backend import (auto_chunk, resolve_round_backend,
                                    round_score_auto)
+from repro.obs.spans import span
 
 from .acquisition import imoo_scores, imoo_scores_batch, mes_information_gain
 from .gp import (JITTER, PAD_BUCKET, GPParams, _default_params, _fit, _kernel,
                  _mm, _standardize, fit_gp, fit_gp_batch, pad_training)
 
-__all__ = ["BOEngine", "BatchedBOEngine", "EngineStats", "FANTASY_MODES",
-           "PROFILE_STAGES"]
+__all__ = ["BOEngine", "BatchedBOEngine", "EngineStats", "FANTASY_MODES"]
 
 #: supported imputation rules for fantasy (q-batch / pending) selection:
 #: ``"mean"`` — posterior mean at the pick (kriging believer); ``"cl_min"`` /
@@ -138,11 +137,6 @@ class EngineStats:
     pool_appends: int = 0
     pool_replacements: int = 0
     v_chunk_refreshes: int = 0
-    #: cumulative per-stage wall seconds of profiled rounds (only populated
-    #: by ``BOEngine(profile_stages=True)``): keys "fit", "factor",
-    #: "v_update", "frontier", "moments", "score", "argmax" plus
-    #: "round_total" measured around the whole staged sequence.
-    stage_wall_s: dict = dataclasses.field(default_factory=dict)
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -154,20 +148,13 @@ class EngineStats:
         written before a stats field existed (and ones written after a field
         this build doesn't know about) both load."""
         fields = {f.name for f in dataclasses.fields(cls)}
-        kept = {k: v for k, v in d.items() if k in fields}
-        if kept.get("stage_wall_s") is not None:
-            # defensive copy: never alias the caller's (checkpoint) dict
-            kept["stage_wall_s"] = {str(k): float(v)
-                                    for k, v in kept["stage_wall_s"].items()}
-        return cls(**kept)
+        return cls(**{k: v for k, v in d.items() if k in fields})
 
     def fold_into(self, registry, *, prefix: str = "engine") -> None:
         """Accumulate this run's counters into an
         :class:`repro.obs.MetricsRegistry` (duck-typed — anything with
         ``counter(name, help).inc(v, **labels)``). Call ONCE per finished
-        engine (the stats are cumulative over its lifetime); the
-        ``stage_wall_s`` breakdown lands as
-        ``engine_stage_seconds_total{stage=...}``."""
+        engine (the stats are cumulative over its lifetime)."""
         for k in ("rounds", "refactors", "block_updates", "dispatches",
                   "fantasy_steps", "frontier_resamples",
                   "scenario_refactors", "scenario_block_updates",
@@ -177,12 +164,6 @@ class EngineStats:
             if v:
                 registry.counter(f"{prefix}_{k}_total",
                                  f"engine {k.replace('_', ' ')}").inc(v)
-        for stage, s in (self.stage_wall_s or {}).items():
-            registry.counter(
-                f"{prefix}_stage_seconds_total",
-                "profiled per-stage wall seconds"
-                " (profile_stages=True rounds only)",
-            ).inc(float(s), stage=str(stage))
 
 
 class EngineState(NamedTuple):
@@ -416,46 +397,56 @@ def _round_seq(state: EngineState, rows_pad, y_pad, mask, pool_c, evalm_c,
     launch per pool chunk (``kernels/round_fused``) that keeps the V update,
     posterior moments, MES scores and the running argmax in VMEM. It selects
     the identical candidate (first-index-wins ties included — pinned by
-    ``tests/test_kernels.py``); ``fused=False`` keeps the historical HLO
-    byte-identical, which is what the golden trajectory fixtures pin."""
+    ``tests/test_kernels.py``); ``fused=False`` keeps the historical
+    computation op for op, which is what the golden trajectory fixtures
+    pin.
+
+    The stages carry ``jax.named_scope`` names (``fit``, ``factor``,
+    ``v_update``, ``frontier``, ``score``; the fused launch, which updates
+    V and scores in one kernel, is ``score``), so the device trace can be
+    split by stage; a scope changes op metadata only."""
     nc, C, d = pool_c.shape
     pool_flat = pool_c.reshape(nc * C, d)
-    x = pool_flat[rows_pad] + 10.0 * mask[:, None]  # pad_training's x rule
-    yn, y_mean, y_std = _standardize(y_pad, mask)
-    params = _fit(state.params, x, yn, mask, steps=steps)
-    drift = _drift(params, state.params_ref)
-    if s0 <= 0:  # statically known: nothing reusable — always refactor
-        do_ref = jnp.asarray(True)
-    else:
-        do_ref = jnp.logical_or(force_refactor, drift > drift_tol)
-    # On refactor the factorization adopts the fresh fit; resolve params_ref
-    # first so both L/V branches below factor under the same snapshot.
-    params_ref = jax.tree.map(lambda a, b: jnp.where(do_ref, a, b),
-                              params, state.params_ref)
-    if s0 <= 0:
-        L = _chol_refactor(params_ref, x, mask)
-    else:
-        L = jax.lax.cond(
-            do_ref,
-            lambda: _chol_refactor(params_ref, x, mask),
-            lambda: _chol_block(params_ref, state.L, x, mask, s0))
+    with jax.named_scope("fit"):
+        x = pool_flat[rows_pad] + 10.0 * mask[:, None]  # pad_training's rule
+        yn, y_mean, y_std = _standardize(y_pad, mask)
+        params = _fit(state.params, x, yn, mask, steps=steps)
+        drift = _drift(params, state.params_ref)
+    with jax.named_scope("factor"):
+        if s0 <= 0:  # statically known: nothing reusable — always refactor
+            do_ref = jnp.asarray(True)
+        else:
+            do_ref = jnp.logical_or(force_refactor, drift > drift_tol)
+        # On refactor the factorization adopts the fresh fit; resolve
+        # params_ref first so both L/V branches factor under one snapshot.
+        params_ref = jax.tree.map(lambda a, b: jnp.where(do_ref, a, b),
+                                  params, state.params_ref)
+        if s0 <= 0:
+            L = _chol_refactor(params_ref, x, mask)
+        else:
+            L = jax.lax.cond(
+                do_ref,
+                lambda: _chol_refactor(params_ref, x, mask),
+                lambda: _chol_block(params_ref, state.L, x, mask, s0))
 
     if fused and select:
         # Fused round: beta/y* first (independent of V), then one Pallas
         # launch per chunk does V-update + moments + MES + argmax in VMEM.
-        beta, ystar = _beta_ystar(params_ref, L, x, yn, y_mean, y_std,
-                                  pool_c, sub_rows, key, s=s)
+        with jax.named_scope("frontier"):
+            beta, ystar = _beta_ystar(params_ref, L, x, yn, y_mean, y_std,
+                                      pool_c, sub_rows, key, s=s)
 
         def _fused(s0f):
             return round_score_auto(params_ref, L, state.V, x, beta, ystar,
                                     pool_c, evalm_c, base, y_mean, y_std,
                                     weights, s0=s0f, backend="pallas")
 
-        if s0 <= 0:
-            V, nxt = _fused(0)
-        else:
-            V, nxt = jax.lax.cond(do_ref, lambda: _fused(0),
-                                  lambda: _fused(s0))
+        with jax.named_scope("score"):
+            if s0 <= 0:
+                V, nxt = _fused(0)
+            else:
+                V, nxt = jax.lax.cond(do_ref, lambda: _fused(0),
+                                      lambda: _fused(s0))
         return EngineState(params, params_ref, L, V), nxt, do_ref, drift, \
             ystar
 
@@ -468,83 +459,18 @@ def _round_seq(state: EngineState, rows_pad, y_pad, mask, pool_c, evalm_c,
             lambda: _v_chunk_refactor(params_ref, L, x, pc),
             lambda: _v_chunk_block(params_ref, L, Vc_old, x, pc, s0))
 
-    _, V = jax.lax.scan(vstep, None, (state.V, pool_c))
-    beta, ystar = _beta_ystar(params_ref, L, x, yn, y_mean, y_std, pool_c,
-                              sub_rows, key, s=s)
+    with jax.named_scope("v_update"):
+        _, V = jax.lax.scan(vstep, None, (state.V, pool_c))
+    with jax.named_scope("frontier"):
+        beta, ystar = _beta_ystar(params_ref, L, x, yn, y_mean, y_std,
+                                  pool_c, sub_rows, key, s=s)
     if select:
-        nxt = _select_chunks(params_ref, beta, ystar, V, y_mean, y_std,
-                             evalm_c, base, weights)
+        with jax.named_scope("score"):
+            nxt = _select_chunks(params_ref, beta, ystar, V, y_mean, y_std,
+                                 evalm_c, base, weights)
     else:
         nxt = jnp.asarray(-1, jnp.int32)
     return EngineState(params, params_ref, L, V), nxt, do_ref, drift, ystar
-
-
-# ------------------------------------------------- staged round (profiler)
-# ``BOEngine(profile_stages=True)`` replaces the one-dispatch ``_round_seq``
-# with these separately-jitted stages so each stage's wall time can be
-# measured with ``block_until_ready`` (accumulated in
-# ``EngineStats.stage_wall_s``; surfaced by ``engine_bench --profile``).
-# This is a MEASUREMENT mode: the staged math is the same formula set, but
-# splitting the dispatch changes XLA's fusion schedule, so a profiled
-# trajectory is allclose — not bitwise — to the fused-dispatch one.
-def _stage_fit_impl(params, params_ref, pool_flat, rows_pad, y_pad, mask, *,
-                    steps: int):
-    x = pool_flat[rows_pad] + 10.0 * mask[:, None]
-    yn, y_mean, y_std = _standardize(y_pad, mask)
-    p2 = _fit(params, x, yn, mask, steps=steps)
-    return p2, _drift(p2, params_ref), x, yn, y_mean, y_std
-
-
-def _stage_v_impl(params_ref, L, V, x, pool_c, *, s0: int):
-    if s0 <= 0:
-        _, Vn = jax.lax.scan(
-            lambda _, pc: (None, _v_chunk_refactor(params_ref, L, x, pc)),
-            None, pool_c)
-    else:
-        _, Vn = jax.lax.scan(
-            lambda _, inp: (None, _v_chunk_block(params_ref, L, inp[0], x,
-                                                 inp[1], s0)),
-            None, (V, pool_c))
-    return Vn
-
-
-def _stage_moments_impl(params_ref, beta, V):
-    _, ms = jax.lax.scan(
-        lambda _, Vc: (None, jax.vmap(_col_moments)(params_ref.log_var,
-                                                    beta, Vc)),
-        None, V)
-    return ms  # (mean [nc, m, C], std [nc, m, C])
-
-
-def _stage_score_impl(mean, std, y_mean, y_std, ystar, evalm_c, weights):
-    def one(_, inp):
-        mn, sd, em = inp
-        sc = mes_information_gain(mn.T * y_std + y_mean, sd.T * y_std,
-                                  ystar, weights)
-        return None, jnp.where(em, -jnp.inf, sc)
-
-    _, scores = jax.lax.scan(one, None, (mean, std, evalm_c))
-    return scores  # [nc, C]
-
-
-def _stage_argmax_impl(scores):
-    # chunks are laid out contiguously (base[j] = j·C), so the flat argmax
-    # IS the global first-index-wins pick of the scanned running-max carry
-    return jnp.argmax(scores.reshape(-1)).astype(jnp.int32)
-
-
-_stage_fit = jax.jit(_stage_fit_impl, static_argnames=("steps",))
-_stage_chol_refactor = jax.jit(_chol_refactor)
-_stage_chol_block = jax.jit(_chol_block, static_argnames=("s0",))
-_stage_v = jax.jit(_stage_v_impl, static_argnames=("s0",))
-_stage_frontier = jax.jit(_beta_ystar, static_argnames=("s",))
-_stage_moments = jax.jit(_stage_moments_impl)
-_stage_score = jax.jit(_stage_score_impl)
-_stage_argmax = jax.jit(_stage_argmax_impl)
-
-#: stage keys a profiled select round populates, in execution order.
-PROFILE_STAGES = ("fit", "factor", "v_update", "frontier", "moments",
-                  "score", "argmax")
 
 
 # ------------------------------------------------------- fantasy (q-batch)
@@ -713,7 +639,9 @@ def _phase1_batch_impl(params, params_ref, pool_flat, rows_pad, y_pad, mask,
         p2 = _fit(p, x, yn, mi, steps=steps)
         return p2, _drift(p2, pref), x, yn, y_mean, y_std
 
-    return jax.vmap(one)(params, params_ref, pool_flat, rows_pad, y_pad, mask)
+    with jax.named_scope("fit"):
+        return jax.vmap(one)(params, params_ref, pool_flat, rows_pad, y_pad,
+                             mask)
 
 
 def _refactor_select_batch_impl(params, x, mask, pool_c, base, yn, y_mean,
@@ -721,19 +649,28 @@ def _refactor_select_batch_impl(params, x, mask, pool_c, base, yn, y_mean,
                                 s: int, select: bool = True,
                                 fused: bool = False):
     def one(p, xi, mi, pci, bi, yni, ym, ys, sr, em, k, w):
-        L = _chol_refactor(p, xi, mi)
+        with jax.named_scope("factor"):
+            L = _chol_refactor(p, xi, mi)
         if fused and select:
             nc, C, _ = pci.shape
-            beta, ystar = _beta_ystar(p, L, xi, yni, ym, ys, pci, sr, k, s=s)
-            V0 = jnp.zeros((nc, L.shape[0], L.shape[1], C), jnp.float32)
-            V, nxt = round_score_auto(p, L, V0, xi, beta, ystar, pci, em, bi,
-                                      ym, ys, w, s0=0, backend="pallas")
+            with jax.named_scope("frontier"):
+                beta, ystar = _beta_ystar(p, L, xi, yni, ym, ys, pci, sr, k,
+                                          s=s)
+            with jax.named_scope("score"):
+                V0 = jnp.zeros((nc, L.shape[0], L.shape[1], C), jnp.float32)
+                V, nxt = round_score_auto(p, L, V0, xi, beta, ystar, pci, em,
+                                          bi, ym, ys, w, s0=0,
+                                          backend="pallas")
             return L, V, nxt, ystar
-        _, V = jax.lax.scan(
-            lambda _, pc: (None, _v_chunk_refactor(p, L, xi, pc)), None, pci)
-        beta, ystar = _beta_ystar(p, L, xi, yni, ym, ys, pci, sr, k, s=s)
+        with jax.named_scope("v_update"):
+            _, V = jax.lax.scan(
+                lambda _, pc: (None, _v_chunk_refactor(p, L, xi, pc)), None,
+                pci)
+        with jax.named_scope("frontier"):
+            beta, ystar = _beta_ystar(p, L, xi, yni, ym, ys, pci, sr, k, s=s)
         if select:
-            nxt = _select_chunks(p, beta, ystar, V, ym, ys, em, bi, w)
+            with jax.named_scope("score"):
+                nxt = _select_chunks(p, beta, ystar, V, ym, ys, em, bi, w)
         else:
             nxt = jnp.asarray(-1, jnp.int32)
         return L, V, nxt, ystar
@@ -747,21 +684,27 @@ def _update_select_batch_impl(params_ref, L, V, x, mask, pool_c, base, yn,
                               *, s: int, s0: int, select: bool = True,
                               fused: bool = False):
     def one(p, Li, Vi, xi, mi, pci, bi, yni, ym, ys, sr, em, k, w):
-        Ln = _chol_block(p, Li, xi, mi, s0)
+        with jax.named_scope("factor"):
+            Ln = _chol_block(p, Li, xi, mi, s0)
         if fused and select:
-            beta, ystar = _beta_ystar(p, Ln, xi, yni, ym, ys, pci, sr, k,
-                                      s=s)
-            Vn, nxt = round_score_auto(p, Ln, Vi, xi, beta, ystar, pci, em,
-                                       bi, ym, ys, w, s0=s0,
-                                       backend="pallas")
+            with jax.named_scope("frontier"):
+                beta, ystar = _beta_ystar(p, Ln, xi, yni, ym, ys, pci, sr, k,
+                                          s=s)
+            with jax.named_scope("score"):
+                Vn, nxt = round_score_auto(p, Ln, Vi, xi, beta, ystar, pci,
+                                           em, bi, ym, ys, w, s0=s0,
+                                           backend="pallas")
             return Ln, Vn, nxt, ystar
-        _, Vn = jax.lax.scan(
-            lambda _, inp: (None, _v_chunk_block(p, Ln, inp[0], xi, inp[1],
-                                                 s0)),
-            None, (Vi, pci))
-        beta, ystar = _beta_ystar(p, Ln, xi, yni, ym, ys, pci, sr, k, s=s)
+        with jax.named_scope("v_update"):
+            _, Vn = jax.lax.scan(
+                lambda _, inp: (None, _v_chunk_block(p, Ln, inp[0], xi,
+                                                     inp[1], s0)),
+                None, (Vi, pci))
+        with jax.named_scope("frontier"):
+            beta, ystar = _beta_ystar(p, Ln, xi, yni, ym, ys, pci, sr, k, s=s)
         if select:
-            nxt = _select_chunks(p, beta, ystar, Vn, ym, ys, em, bi, w)
+            with jax.named_scope("score"):
+                nxt = _select_chunks(p, beta, ystar, Vn, ym, ys, em, bi, w)
         else:
             nxt = jnp.asarray(-1, jnp.int32)
         return Ln, Vn, nxt, ystar
@@ -1273,8 +1216,7 @@ class BOEngine(_EngineBase):
                  warm_start: bool | None = None, gp_steps: int = 150,
                  warm_steps: int | None = None, drift_tol: float = 1.0,
                  bucket: int = PAD_BUCKET, s_frontiers: int = 10,
-                 weights=None, pool_chunk: int | str | None = None,
-                 profile_stages: bool = False):
+                 weights=None, pool_chunk: int | str | None = None):
         self.pool = jnp.asarray(pool_icd, jnp.float32)      # [N, d], once
         self.N, self.d = self.pool.shape
         self._configure(incremental=incremental, warm_start=warm_start,
@@ -1282,15 +1224,6 @@ class BOEngine(_EngineBase):
                         drift_tol=drift_tol, bucket=bucket,
                         s_frontiers=s_frontiers, weights=weights)
         self._setup_chunks(pool_chunk)
-        # profile_stages: run select rounds as separately-timed stage
-        # dispatches instead of one fused program; per-stage wall seconds
-        # accumulate in ``stats.stage_wall_s`` (measurement mode — allclose,
-        # not bitwise, to the one-dispatch round; see the staged-round
-        # section above). Requires incremental=True to mean anything.
-        if profile_stages and not incremental:
-            raise ValueError("profile_stages requires incremental=True: the "
-                             "exact historical path has no staged round")
-        self.profile_stages = bool(profile_stages)
 
         self._rows: list[int] = []
         self._y: np.ndarray | None = None       # [k, m] raw minimized metrics
@@ -1313,9 +1246,11 @@ class BOEngine(_EngineBase):
             raise ValueError(f"observe: {len(rows)} rows but {y.shape[0]} metric rows")
         if not rows:
             return
-        self._rows.extend(rows)
-        self._y = y if self._y is None else np.concatenate([self._y, y], 0)
-        self._eval_mask = self._eval_mask.at[np.asarray(rows)].set(True)
+        with span("engine.observe"):
+            self._rows.extend(rows)
+            self._y = (y if self._y is None
+                       else np.concatenate([self._y, y], 0))
+            self._eval_mask = self._eval_mask.at[np.asarray(rows)].set(True)
 
     @property
     def m(self) -> int:
@@ -1406,22 +1341,24 @@ class BOEngine(_EngineBase):
         to_append = list(pending)
         appended = 0
         try:
-            while len(picks) < q:
-                if not to_append:
-                    to_append.append(picks[-1])
-                row = to_append.pop(0)
-                need_pick = not to_append  # last append before a fresh pick
-                L, V, rows_pad, mask_j, yn, evalm, nxt = _fantasy_step(
-                    state.params_ref, L, V, rows_pad, yn, mask_j,
-                    self._pool_c, evalm, self._base, weights, y_mean, y_std,
-                    ystar, jnp.asarray(row, jnp.int32),
-                    jnp.asarray(n + appended, jnp.int32),
-                    s0=s0, liar=fantasy, return_pick=need_pick, fused=fused)
-                appended += 1
-                self.stats.fantasy_steps += 1
-                self.stats.dispatches += 1
-                if need_pick:
-                    picks.append(int(nxt))
+            with span("engine.fantasy"):
+                while len(picks) < q:
+                    if not to_append:
+                        to_append.append(picks[-1])
+                    row = to_append.pop(0)
+                    need_pick = not to_append  # last append before a pick
+                    L, V, rows_pad, mask_j, yn, evalm, nxt = _fantasy_step(
+                        state.params_ref, L, V, rows_pad, yn, mask_j,
+                        self._pool_c, evalm, self._base, weights, y_mean,
+                        y_std, ystar, jnp.asarray(row, jnp.int32),
+                        jnp.asarray(n + appended, jnp.int32),
+                        s0=s0, liar=fantasy, return_pick=need_pick,
+                        fused=fused)
+                    appended += 1
+                    self.stats.fantasy_steps += 1
+                    self.stats.dispatches += 1
+                    if need_pick:
+                        picks.append(int(nxt))
         except BaseException:
             # The chain donated the live L/V buffers; a partial chain would
             # leave self._state referencing deleted storage. Drop to a cold
@@ -1440,22 +1377,29 @@ class BOEngine(_EngineBase):
     def _select_exact(self, key, sub_rows) -> int:
         """The historical from-scratch round, call-for-call (bit-exact)."""
         rows = np.asarray(self._rows)
-        x_train = self.pool[rows]
-        state = fit_gp(x_train, jnp.asarray(-self._y, jnp.float32),
-                       steps=self.gp_steps,
-                       params=self._last_params if self.warm_start else None,
-                       bucket=self.bucket)
-        self._last_params = state.params
-        fc = (self.pool if sub_rows is None
-              else self.pool[np.asarray(sub_rows)])
-        scores = np.array(imoo_scores(state, self.pool, key,
-                                      s=self.s_frontiers, frontier_cand=fc,
-                                      weights=self.weights))
-        scores[rows] = -np.inf  # never re-evaluate
-        self.stats.rounds += 1
-        self.stats.dispatches += self.EXACT_DISPATCHES_PER_ROUND
-        self._n_at_last_select = len(self._rows)
-        return int(np.argmax(scores))
+        P = len(rows) + (-len(rows)) % self.bucket
+        with span("engine.select", path="exact", P=P):
+            with span("engine.fit"):
+                x_train = self.pool[rows]
+                state = fit_gp(x_train, jnp.asarray(-self._y, jnp.float32),
+                               steps=self.gp_steps,
+                               params=(self._last_params if self.warm_start
+                                       else None),
+                               bucket=self.bucket)
+            self._last_params = state.params
+            with span("engine.score"):
+                fc = (self.pool if sub_rows is None
+                      else self.pool[np.asarray(sub_rows)])
+                scores = imoo_scores(state, self.pool, key,
+                                     s=self.s_frontiers, frontier_cand=fc,
+                                     weights=self.weights)
+            with span("engine.fetch"):
+                scores = np.array(scores)
+            scores[rows] = -np.inf  # never re-evaluate
+            self.stats.rounds += 1
+            self.stats.dispatches += self.EXACT_DISPATCHES_PER_ROUND
+            self._n_at_last_select = len(self._rows)
+            return int(np.argmax(scores))
 
     def _select_incremental(self, key, sub_rows, *, reserve: int = 0,
                             do_select: bool = True) -> int:
@@ -1467,105 +1411,49 @@ class BOEngine(_EngineBase):
         n = len(self._rows)
         P = n + reserve
         P = P + (-P) % self.bucket
-        grew = P != self._P
-        first = self._state is None
-        rows_pad, y_pad, mask = self._padded_batch(self._rows, self._y, P)
-        sub = (np.arange(self.N, dtype=np.int32) if sub_rows is None
-               else np.asarray(sub_rows, np.int32))
-        weights = (jnp.ones((self.m,), jnp.float32) if self.weights is None
-                   else self.weights)
+        with span("engine.select", path="incremental", P=P) as sp:
+            grew = P != self._P
+            first = self._state is None
+            with span("engine.pack"):
+                rows_pad, y_pad, mask = self._padded_batch(self._rows,
+                                                           self._y, P)
+                sub = (np.arange(self.N, dtype=np.int32) if sub_rows is None
+                       else np.asarray(sub_rows, np.int32))
+                weights = (jnp.ones((self.m,), jnp.float32)
+                           if self.weights is None else self.weights)
+                cold, steps = self._fit_schedule(first)
+                params0 = (_default_params(self.m, self.d) if cold
+                           else self._state.params)
+                s0 = 0 if (first or grew) else \
+                    (self._n_at_last_select // self.bucket) * self.bucket
+                state = self._alloc_state(params0, P, first or grew)
+                evalm = self._evalm_chunks()
+            with span("engine.launch"):
+                fused = resolve_round_backend("auto", self.N) == "pallas"
+                state, nxt, did_ref, drift, ystar = _round_seq(
+                    state, rows_pad, y_pad, mask, self._pool_c, evalm,
+                    self._base, jnp.asarray(sub), key, bool(first or grew),
+                    self.drift_tol, weights, steps=steps, s=self.s_frontiers,
+                    s0=s0, select=do_select, fused=fused)
 
-        cold, steps = self._fit_schedule(first)
-        params0 = (_default_params(self.m, self.d) if cold
-                   else self._state.params)
-        s0 = 0 if (first or grew) else \
-            (self._n_at_last_select // self.bucket) * self.bucket
-        state = self._alloc_state(params0, P, first or grew)
-
-        if self.profile_stages:
-            state, nxt, did_ref, drift, ystar = self._round_staged(
-                state, rows_pad, y_pad, mask, jnp.asarray(sub), key,
-                bool(first or grew), weights, steps=steps, s0=s0,
-                select=do_select)
-            # the shared bookkeeping below counts 1 dispatch per round; a
-            # staged round launches one program per stage instead
-            self.stats.dispatches += (len(PROFILE_STAGES) if do_select
-                                      else len(PROFILE_STAGES) - 3) - 1
-        else:
-            fused = resolve_round_backend("auto", self.N) == "pallas"
-            state, nxt, did_ref, drift, ystar = _round_seq(
-                state, rows_pad, y_pad, mask, self._pool_c,
-                self._evalm_chunks(), self._base, jnp.asarray(sub), key,
-                bool(first or grew), self.drift_tol, weights, steps=steps,
-                s=self.s_frontiers, s0=s0, select=do_select, fused=fused)
-
-        self._state = state
-        self._P = P
-        self._n_at_last_select = n
-        self._last_batch = (rows_pad, y_pad, mask)
-        self._last_ystar = ystar
-        self.stats.rounds += 1
-        self.stats.dispatches += 1
-        self.stats.frontier_resamples += 1
-        self.stats.last_drift = float(drift)
-        if bool(did_ref):
-            self.stats.refactors += 1
-        else:
-            self.stats.block_updates += 1
-        return int(nxt)
-
-    def _round_staged(self, state, rows_pad, y_pad, mask, sub, key,
-                      force_refactor: bool, weights, *, steps: int, s0: int,
-                      select: bool):
-        """One round as separately-timed stage dispatches (profile mode).
-
-        Mirrors ``_round_seq``'s math and refactor policy stage by stage;
-        every stage is timed with ``block_until_ready`` and accumulated into
-        ``stats.stage_wall_s`` (plus ``"round_total"`` around the whole
-        sequence, so ``sum(stages) / round_total`` reports the host-side
-        orchestration overhead the fused dispatch avoids)."""
-        t_round = time.perf_counter()
-
-        def timed(name, fn, *args, **kw):
-            t0 = time.perf_counter()
-            out = fn(*args, **kw)
-            jax.block_until_ready(out)
-            acc = self.stats.stage_wall_s
-            acc[name] = acc.get(name, 0.0) + (time.perf_counter() - t0)
-            return out
-
-        pool_flat = self._pool_c.reshape(self._N_pad, self.d)
-        params, drift, x, yn, y_mean, y_std = timed(
-            "fit", _stage_fit, state.params, state.params_ref, pool_flat,
-            jnp.asarray(rows_pad), jnp.asarray(y_pad), jnp.asarray(mask),
-            steps=steps)
-        # host-side twin of _round_seq's in-graph refactor decision
-        do_ref = (force_refactor or s0 <= 0
-                  or float(drift) > self.drift_tol)
-        params_ref = params if do_ref else state.params_ref
-        mask_j = jnp.asarray(mask)
-        if do_ref:
-            L = timed("factor", _stage_chol_refactor, params_ref, x, mask_j)
-        else:
-            L = timed("factor", _stage_chol_block, params_ref, state.L, x,
-                      mask_j, s0=s0)
-        V = timed("v_update", _stage_v, params_ref, L, state.V, x,
-                  self._pool_c, s0=0 if do_ref else s0)
-        beta, ystar = timed("frontier", _stage_frontier, params_ref, L, x,
-                            yn, y_mean, y_std, self._pool_c, sub, key,
-                            s=self.s_frontiers)
-        if select:
-            mean, std = timed("moments", _stage_moments, params_ref, beta, V)
-            scores = timed("score", _stage_score, mean, std, y_mean, y_std,
-                           ystar, self._evalm_chunks(), weights)
-            nxt = timed("argmax", _stage_argmax, scores)
-        else:
-            nxt = jnp.asarray(-1, jnp.int32)
-        acc = self.stats.stage_wall_s
-        acc["round_total"] = (acc.get("round_total", 0.0)
-                              + (time.perf_counter() - t_round))
-        return (EngineState(params, params_ref, L, V), nxt,
-                jnp.asarray(do_ref), drift, ystar)
+            self._state = state
+            self._P = P
+            self._n_at_last_select = n
+            self._last_batch = (rows_pad, y_pad, mask)
+            self._last_ystar = ystar
+            # the host's only waits on the round's device result
+            with span("engine.fetch"):
+                drift, did_ref, nxt = float(drift), bool(did_ref), int(nxt)
+            sp.set(refactor=did_ref)
+            self.stats.rounds += 1
+            self.stats.dispatches += 1
+            self.stats.frontier_resamples += 1
+            self.stats.last_drift = drift
+            if did_ref:
+                self.stats.refactors += 1
+            else:
+                self.stats.block_updates += 1
+            return nxt
 
     # ------------------------------------------------------------- helpers
     @staticmethod

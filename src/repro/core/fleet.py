@@ -45,6 +45,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import log_progress
+
 from .engine import BatchedBOEngine
 from .icd import icd_from_data
 from .pareto import pareto_mask
@@ -305,7 +307,6 @@ def _log_round(st: _ScenarioState, i: int, label: str,
                reference_front: np.ndarray | None, verbose: bool,
                tag: str = "fleet", wall_s: float | None = None,
                events=None) -> None:
-    from repro.obs import log_progress  # deferred: obs imports core.tuner
     log_progress(st.history, st.y, len(st.evaluated), i, reference_front,
                  verbose=verbose, tag=tag, label=label, wall_s=wall_s,
                  events=events)

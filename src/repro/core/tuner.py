@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import log_progress, span
+
 from .engine import BOEngine
 from .icd import icd_from_data
 from .pareto import adrs, pareto_mask
@@ -103,25 +105,29 @@ def explore_prologue(space: DesignSpace, pool_idx: np.ndarray, flow: FlowFn,
     identically. A checkpoint resume replays everything after the flow
     calls from the stored ``v`` instead (see :func:`_prologue_from_v`).
     """
-    N = pool_idx.shape[0]
-    # Line 1: v = ICD(X, n). Trials are drawn from the pool so their metrics
-    # can seed the GP (the paper's flow budget accounting does the same: the
-    # n importance trials are real evaluations).
-    trial_rows, key = icd_trial_rows(key, N, n)
-    trial_y = np.asarray(flow(pool_idx[trial_rows]))
-    v = icd_from_data(space, pool_idx[trial_rows], trial_y)
+    with span("tuner.prologue"):
+        N = pool_idx.shape[0]
+        # Line 1: v = ICD(X, n). Trials are drawn from the pool so their
+        # metrics can seed the GP (the paper's flow budget accounting does the
+        # same: the n importance trials are real evaluations).
+        trial_rows, key = icd_trial_rows(key, N, n)
+        with span("tuner.flow"):
+            trial_y = np.asarray(flow(pool_idx[trial_rows]))
+        v = icd_from_data(space, pool_idx[trial_rows], trial_y)
 
-    # Line 2: Z = SoC-Init(X, µ, b, v, v_th)  (prune + ICD transform + TED)
-    init_rows, pruned, pool_icd = soc_init(
-        space, pool_idx, v, v_th=v_th, b=b, mu=mu, use_kernel=use_kernels)
-    pool_icd = jnp.asarray(pool_icd, jnp.float32)
+        # Line 2: Z = SoC-Init(X, µ, b, v, v_th) (prune + ICD transform + TED)
+        init_rows, pruned, pool_icd = soc_init(
+            space, pool_idx, v, v_th=v_th, b=b, mu=mu,
+            use_kernel=use_kernels)
+        pool_icd = jnp.asarray(pool_icd, jnp.float32)
 
-    # Line 4: y <- VLSIFlow(Z)
-    evaluated: list[int] = list(dict.fromkeys(int(r) for r in init_rows))
-    y_init = np.asarray(flow(pool_idx[np.asarray(evaluated)]))
-    evaluated, y = merge_trial_evals(evaluated, y_init, trial_rows, trial_y,
-                                     reuse_icd_trials)
-    return key, v, pruned, pool_icd, evaluated, y
+        # Line 4: y <- VLSIFlow(Z)
+        evaluated: list[int] = list(dict.fromkeys(int(r) for r in init_rows))
+        with span("tuner.flow"):
+            y_init = np.asarray(flow(pool_idx[np.asarray(evaluated)]))
+        evaluated, y = merge_trial_evals(evaluated, y_init, trial_rows,
+                                         trial_y, reuse_icd_trials)
+        return key, v, pruned, pool_icd, evaluated, y
 
 
 def _prologue_from_v(space: DesignSpace, pool_idx: np.ndarray, v: np.ndarray,
@@ -189,7 +195,6 @@ def soc_tuner(
     warm_steps: int | None = None,
     drift_tol: float = 1.0,
     pool_chunk: int | str | None = None,
-    profile_stages: bool = False,
     q: int = 1,
     fantasy: str = "mean",
     checkpoint_dir: str | None = None,
@@ -219,10 +224,9 @@ def soc_tuner(
     ``pool_chunk`` (int | ``"auto"``; requires ``incremental=True``) streams
     the engine's O(N) pool state in column chunks so ``n_pool`` can grow to
     10⁵–10⁶ candidates — identical selections at any chunk size; see
-    ``docs/scaling.md``. ``profile_stages`` (requires ``incremental=True``)
-    times every round stage separately and accumulates the wall seconds in
-    the result's ``engine_stats["stage_wall_s"]`` (surfaced by
-    ``engine_bench --profile``).
+    ``docs/scaling.md``. Under a ``jax.profiler`` trace every round and its
+    layers are spans (``tuner.round``, ``engine.select``, ...;
+    :mod:`repro.obs.spans`) beside the device ops.
 
     ``q`` (requires ``incremental=True`` when > 1) selects q candidates per
     round via fantasy updates (``BOEngine.select_q``; ``fantasy`` picks the
@@ -305,8 +309,6 @@ def soc_tuner(
         y = np.asarray(snap["y"], np.float32)
         key = jnp.asarray(snap["key"])
 
-    from repro.obs import log_progress  # deferred: obs imports this module
-
     history: list[dict] = [] if snap is None else list(snap["history"])
     t_round = time.monotonic()
 
@@ -330,8 +332,7 @@ def soc_tuner(
                       warm_start=warm_start, gp_steps=gp_steps,
                       warm_steps=warm_steps, drift_tol=drift_tol,
                       s_frontiers=s_frontiers, weights=w,
-                      pool_chunk=pool_chunk,
-                      profile_stages=profile_stages)
+                      pool_chunk=pool_chunk)
     if snap is None:
         engine.observe(evaluated, y)
     else:
@@ -354,37 +355,43 @@ def soc_tuner(
         prune_snapshots(checkpoint_dir)
 
     for it in range(start_round, T):
-        key, k_fit, k_acq, k_sub = jax.random.split(key, 4)
-        del k_fit  # reserved slot — keeps the key schedule seed-stable
+        with span("tuner.round", round=it + 1):
+            with span("tuner.keys"):
+                key, k_fit, k_acq, k_sub = jax.random.split(key, 4)
+                del k_fit  # reserved slot — keeps the key schedule stable
+                # Frontier sampling over a subset (O(q³) Cholesky), scoring
+                # over all.
+                sub = frontier_subset_rows(k_sub, N, frontier_subset)
+            picks = engine.select_q(k_acq, q, sub_rows=sub, fantasy=fantasy)
 
-        # Frontier sampling over a subset (O(q³) Cholesky), scoring over all.
-        sub = frontier_subset_rows(k_sub, N, frontier_subset)
-        picks = engine.select_q(k_acq, q, sub_rows=sub, fantasy=fantasy)
-
-        # Line 8: evaluate and append (one flow call for the whole batch).
-        y_new = np.asarray(flow(pool_idx[np.asarray(picks)]))
-        evaluated.extend(picks)
-        y = np.concatenate([y, y_new], axis=0)
-        engine.observe(picks, y_new)
-        log_round(it + 1)
-        # Between-round proposal (default off): refresh the weakest pool
-        # columns before the next round spends acquisition budget on them.
-        # fold_in keys it off the carried key WITHOUT advancing the split
-        # schedule, and runs before the checkpoint so a killed run resumes
-        # on exactly the pool the next round would have seen. Runs after the
-        # final round too — T may grow across resumes, so the proposal
-        # schedule must not depend on it.
-        if pcfg.enabled and (it + 1) % pcfg.every == 0:
-            out = propose_and_replace(
-                engine, space, jax.random.fold_in(key, PROPOSER_FOLD + it),
-                pool_idx, cfg=pcfg,
-                encode_cols=lambda c: transform_to_icd(
-                    space, pruned.apply_pins(jnp.asarray(c)), v),
-                evaluated=[evaluated], ys=[y], stats=pstats)
-            if out is not None:
-                pool_idx[out.victims] = out.new_idx
-        if checkpoint_dir and (it + 1) % checkpoint_every == 0:
-            save_checkpoint(it + 1)
+            # Line 8: evaluate and append (one flow call for the whole batch).
+            with span("tuner.flow"):
+                y_new = np.asarray(flow(pool_idx[np.asarray(picks)]))
+            evaluated.extend(picks)
+            y = np.concatenate([y, y_new], axis=0)
+            engine.observe(picks, y_new)
+            log_round(it + 1)
+            # Between-round proposal (default off): refresh the weakest pool
+            # columns before the next round spends acquisition budget on
+            # them. fold_in keys it off the carried key WITHOUT advancing the
+            # split schedule, and runs before the checkpoint so a killed run
+            # resumes on exactly the pool the next round would have seen.
+            # Runs after the final round too — T may grow across resumes, so
+            # the proposal schedule must not depend on it.
+            if pcfg.enabled and (it + 1) % pcfg.every == 0:
+                with span("tuner.propose"):
+                    out = propose_and_replace(
+                        engine, space,
+                        jax.random.fold_in(key, PROPOSER_FOLD + it),
+                        pool_idx, cfg=pcfg,
+                        encode_cols=lambda c: transform_to_icd(
+                            space, pruned.apply_pins(jnp.asarray(c)), v),
+                        evaluated=[evaluated], ys=[y], stats=pstats)
+                if out is not None:
+                    pool_idx[out.victims] = out.new_idx
+            if checkpoint_dir and (it + 1) % checkpoint_every == 0:
+                with span("tuner.checkpoint"):
+                    save_checkpoint(it + 1)
 
     front = _front(y)
     rows = np.asarray(evaluated)

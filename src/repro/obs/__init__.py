@@ -1,4 +1,4 @@
-"""Fleet-wide telemetry: metrics registry, event log, trace reports.
+"""Fleet-wide telemetry: metrics registry, event log, spans, trace reports.
 
 The exploration stack's value claim is *efficiency* — evaluations-to-ADRS
 under a VLSI-flow budget — so its runtime behavior (queue depths, flow
@@ -23,6 +23,12 @@ with telemetry fully enabled (proven by ``tests/test_obs.py``).
                shared by ``soc_tuner`` / ``fleet_tuner`` / the service
                runners / server jobs: builds the history record, prints
                the verbose line, and emits the matching event-log record.
+- ``spans``    :func:`span` / :func:`records` — the program's layer
+               boundaries (``tuner.round``, ``engine.select``,
+               ``engine.fetch``, ...) on the JAX profiler's clock: active
+               only while a profiler trace is, then written into the trace
+               beside the device ops and kept in memory for the
+               benchmark's readers; a single ``is_enabled`` check otherwise.
 - ``trace``    :func:`build_chrome_trace` / :func:`summarize_events` —
                render an event log into a Chrome ``trace_event`` JSON
                (loadable in ``chrome://tracing`` / Perfetto) and a per-track
@@ -35,11 +41,13 @@ from .events import EventLog, read_events
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       render_prometheus)
 from .progress import log_progress
+from .spans import SpanRecord, records, span
 from .trace import build_chrome_trace, summarize_events
 
 __all__ = [
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "render_prometheus",
     "EventLog", "read_events",
     "log_progress",
+    "span", "records", "SpanRecord",
     "build_chrome_trace", "summarize_events",
 ]

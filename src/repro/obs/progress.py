@@ -9,11 +9,12 @@ and the in-memory history can never disagree.
 The history record itself still comes from
 :func:`repro.core.tuner.round_record` (the schema the figure scripts and
 ``engine_bench`` read) — this helper adds NOTHING to it, so histories stay
-byte-identical with telemetry on or off.
+byte-identical with telemetry on or off. Under a profiler trace the helper
+is the span ``tuner.record`` (:mod:`repro.obs.spans`).
 """
 from __future__ import annotations
 
-from repro.core.tuner import round_record
+from .spans import span
 
 __all__ = ["log_progress"]
 
@@ -34,23 +35,26 @@ def log_progress(history: list, y, n_evaluated: int, i: int,
     label so per-scenario/per-job rows separate in the Chrome trace.
     Extra keyword fields ride along on the event record only.
     """
-    rec = round_record(y, n_evaluated, i, reference_front, wall_s=wall_s)
-    history.append(rec)
-    if verbose:
-        head = f"[{tag}] "
-        if label is not None:
-            head += f"{label:<24s} "
-        num = f"{i:4d}" if word == "eval" else f"{i:3d}"
-        print(head + f"{word} {num} evals={rec['evaluations']:4d} "
-              f"front={rec['pareto_size']:3d}"
-              + (f" adrs={rec['adrs']:.4f}" if "adrs" in rec else ""))
-    if events is not None:
-        events.instant(
-            "round", cat="progress",
-            track=track if track is not None else (label or tag),
-            round=i, evaluations=rec["evaluations"],
-            pareto_size=rec["pareto_size"],
-            **({"adrs": rec["adrs"]} if "adrs" in rec else {}),
-            **({"wall_s": wall_s} if wall_s is not None else {}),
-            **event_fields)
-    return rec
+    from repro.core.tuner import round_record  # deferred: core imports obs
+
+    with span("tuner.record"):
+        rec = round_record(y, n_evaluated, i, reference_front, wall_s=wall_s)
+        history.append(rec)
+        if verbose:
+            head = f"[{tag}] "
+            if label is not None:
+                head += f"{label:<24s} "
+            num = f"{i:4d}" if word == "eval" else f"{i:3d}"
+            print(head + f"{word} {num} evals={rec['evaluations']:4d} "
+                  f"front={rec['pareto_size']:3d}"
+                  + (f" adrs={rec['adrs']:.4f}" if "adrs" in rec else ""))
+        if events is not None:
+            events.instant(
+                "round", cat="progress",
+                track=track if track is not None else (label or tag),
+                round=i, evaluations=rec["evaluations"],
+                pareto_size=rec["pareto_size"],
+                **({"adrs": rec["adrs"]} if "adrs" in rec else {}),
+                **({"wall_s": wall_s} if wall_s is not None else {}),
+                **event_fields)
+        return rec

@@ -127,9 +127,6 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--events", default=None,
                    help="append telemetry events (JSON lines) to this "
                         "file; render with tools/trace_report.py")
-    p.add_argument("--profile-stages", action="store_true",
-                   help="profile the engine's per-round stage walls "
-                        "(folded into the metrics registry)")
     p.add_argument("--out", default=None,
                    help="write the result (rows, metrics, history, stats) "
                         "as JSON here")
@@ -515,8 +512,7 @@ def main(argv=None) -> int:
         pool_chunk=pool_chunk, cache_dir=a.cache_dir,
         checkpoint_dir=a.checkpoint_dir, checkpoint_every=a.checkpoint_every,
         resume=a.resume, proposer=_proposer_arg(a), verbose=not a.quiet,
-        events=a.events, profile_stages=a.profile_stages,
-        _kill_after=a.kill_after)
+        events=a.events, _kill_after=a.kill_after)
 
     if not a.quiet:
         print(f"[service] {len(res.evaluated_rows)} evaluations, "

@@ -467,10 +467,9 @@ class Job:
             y=st.y, pareto_rows=rows[front], pareto_y=st.y[front],
             history=st.history, wall_s=self.wall_s,
             engine_stats=stats_d)
-        # Fold the finished engine's counters (incl. any stage_wall_s
-        # breakdown) into the registry ONCE, at the terminal transition —
-        # pause/resume restores cumulative stats, so folding at eviction
-        # would double-count.
+        # Fold the finished engine's counters into the registry ONCE, at
+        # the terminal transition — pause/resume restores cumulative stats,
+        # so folding at eviction would double-count.
         self._engine.stats.fold_into(self.metrics)
         if self._pcfg.enabled:
             self._pstats.fold_into(self.metrics)

@@ -98,7 +98,6 @@ def service_tuner(
     verbose: bool = False,
     metrics: MetricsRegistry | None = None,
     events: EventLog | str | None = None,
-    profile_stages: bool = False,
     _kill_after: int | None = None,
 ) -> TunerResult:
     """Run the exploration service; returns ``soc_tuner``'s result layout.
@@ -127,8 +126,8 @@ def service_tuner(
     ``repro.obs``): ``metrics`` joins an existing registry (one is created
     otherwise), ``events`` is an :class:`repro.obs.EventLog` or a path to
     open one (a path is closed on exit; a resumed run appends a new
-    generation), ``profile_stages`` enables the engine's per-stage
-    profiler and folds its wall breakdown into the registry.
+    generation). Under a ``jax.profiler`` trace the engine's and the
+    prologue's spans (:mod:`repro.obs.spans`) land beside the device ops.
     """
     t0 = time.monotonic()
     metrics = MetricsRegistry() if metrics is None else metrics
@@ -213,8 +212,7 @@ def service_tuner(
     engine_kw = dict(incremental=incremental, warm_start=warm_start,
                      gp_steps=gp_steps, warm_steps=warm_steps,
                      drift_tol=drift_tol, s_frontiers=s_frontiers,
-                     weights=w, pool_chunk=pool_chunk,
-                     profile_stages=profile_stages)
+                     weights=w, pool_chunk=pool_chunk)
     if bucket is not None:
         engine_kw["bucket"] = int(bucket)
     engine = BOEngine(pool_icd, **engine_kw)

@@ -7,7 +7,8 @@ The contract under test:
   rendering, event-log schema/generation/torn-tail semantics, Chrome
   trace_event conversion;
 - **zero perturbation**: turning every telemetry knob on (registry, event
-  log, ``profile_stages``) leaves trajectories bitwise identical — pinned
+  log, a profiler trace with its spans) leaves trajectories bitwise
+  identical — pinned
   both A/B (service_tuner with vs without telemetry) and against the
   committed golden fixture (``server_two_jobs`` replayed on a fully
   instrumented server);
@@ -33,8 +34,8 @@ import numpy as np
 import pytest
 
 from repro.obs import (EventLog, MetricsRegistry, build_chrome_trace,
-                       log_progress, read_events, render_prometheus,
-                       summarize_events)
+                       log_progress, read_events, records,
+                       render_prometheus, summarize_events)
 from repro.obs.metrics import DEFAULT_BUCKETS, parse_label_key
 from repro.service import JobSpec, TunerServer, request, service_tuner
 from repro.soc import VLSIFlow
@@ -297,9 +298,12 @@ def test_service_tuner_trajectory_identical_with_telemetry_on(
     ref = service_tuner(space, small_pool, VLSIFlow(space, "resnet50"),
                         **kw)
     reg = MetricsRegistry()
-    obs = service_tuner(space, small_pool, VLSIFlow(space, "resnet50"),
-                        metrics=reg, events=str(tmp_path / "ev.jsonl"),
-                        profile_stages=True, **kw)
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        n0 = len(records())
+        obs = service_tuner(space, small_pool, VLSIFlow(space, "resnet50"),
+                            metrics=reg, events=str(tmp_path / "ev.jsonl"),
+                            **kw)
+        spans = records()[n0:]
     np.testing.assert_array_equal(ref.evaluated_rows, obs.evaluated_rows)
     np.testing.assert_array_equal(ref.y, obs.y)
     assert _strip_wall(ref.history) == _strip_wall(obs.history)
@@ -307,8 +311,11 @@ def test_service_tuner_trajectory_identical_with_telemetry_on(
     snap = reg.snapshot()
     assert snap["counters"]["pool_completed_total"]["series"][""] >= kw["T"]
     assert snap["counters"]["engine_rounds_total"]["series"][""] > 0
-    stages = snap["counters"]["engine_stage_seconds_total"]["series"]
-    assert any(k.startswith("stage=") for k in stages)
+    names = {r.name for r in spans}
+    assert names >= {"tuner.prologue", "tuner.flow", "tuner.record",
+                     "engine.select", "engine.pack", "engine.launch",
+                     "engine.fetch", "engine.fantasy", "engine.observe"}
+    assert all(r.t1 is not None and not r.failed for r in spans)
     recs = read_events(str(tmp_path / "ev.jsonl"))
     assert {r["name"] for r in recs} >= {"pool.submit", "pool.complete",
                                          "round"}
@@ -387,8 +394,7 @@ def test_golden_tuner_fixtures_replay_with_telemetry(tmp_path, space):
                         reference_front=rg._reference_front(space, pool,
                                                             "resnet50"),
                         metrics=MetricsRegistry(),
-                        events=str(tmp_path / "soc.jsonl"),
-                        profile_stages=True, **rg.RUN_KW)
+                        events=str(tmp_path / "soc.jsonl"), **rg.RUN_KW)
     assert [int(r) for r in res.evaluated_rows] == pinned["evaluated_rows"]
     assert float(res.history[-1]["adrs"]) == \
         pytest.approx(pinned["final_adrs"], rel=1e-6)
