@@ -43,10 +43,12 @@ algebraically identical to a full factorization — see
 ``tests/test_engine.py``); staleness is bounded by ``drift_tol`` and by the
 bucket period, never accumulated silently.
 
-``BOEngine(incremental=False)`` is the exact-equivalence escape hatch: it
-executes the historical per-round computation (``fit_gp`` + ``imoo_scores`` +
-host-side masking/argmax) call-for-call, reproducing the seed ``soc_tuner``
-trajectory bit-for-bit. :class:`BatchedBOEngine` is the same engine with a
+``BOEngine(incremental=False)`` is the exact-equivalence escape hatch: every
+round is a from-scratch one (cold ``gp_steps`` fit, full factorization, fresh
+frontier draw, whole-pool MES), run as one ``_round_seq`` dispatch at the
+static ``s0=0`` with a fresh state; it reproduces the seed ``soc_tuner``
+loop (``fit_gp`` + ``imoo_scores`` + host-side masking/argmax) pick for pick
+(``tests/test_engine.py``). :class:`BatchedBOEngine` is the same engine with a
 leading scenario axis — the fleet runner's backend — whose exact path
 likewise reproduces today's ``fit_gp_batch``/``imoo_scores_batch`` rounds.
 ``BatchedBOEngine(..., mesh=...)`` additionally shards the scenario axis over
@@ -99,9 +101,9 @@ from repro.kernels.backend import (auto_chunk, resolve_round_backend,
                                    round_score_auto)
 from repro.obs.spans import span
 
-from .acquisition import imoo_scores, imoo_scores_batch, mes_information_gain
+from .acquisition import imoo_scores_batch, mes_information_gain
 from .gp import (JITTER, PAD_BUCKET, GPParams, _default_params, _fit, _kernel,
-                 _mm, _standardize, fit_gp, fit_gp_batch, pad_training)
+                 _mm, _standardize, fit_gp_batch, pad_training)
 
 __all__ = ["BOEngine", "BatchedBOEngine", "EngineStats", "FANTASY_MODES"]
 
@@ -1196,9 +1198,10 @@ class BOEngine(_EngineBase):
             engine.observe([nxt], flow(pool_idx[nxt][None]))
 
     ``incremental=False`` runs the historical from-scratch round (cold
-    ``fit_gp`` + ``imoo_scores`` + host argmax) and reproduces the seed
-    ``soc_tuner`` trajectory bit-for-bit; see the module docstring for what
-    the incremental path changes and the update/refactor policy.
+    fit, full factorization, whole-pool MES) as one ``_round_seq`` dispatch
+    and reproduces the seed ``soc_tuner`` trajectory; see the module
+    docstring for what the incremental path changes and the update/refactor
+    policy.
 
     ``pool_chunk`` (``None`` | int | ``"auto"``) streams every O(N) pool
     stage in column chunks of that many candidates — same selections at any
@@ -1208,9 +1211,9 @@ class BOEngine(_EngineBase):
     frontier subset is the whole pool, and the joint frontier draw is O(q³).
     """
 
-    #: jitted program launches of one exact-path round (fit, posterior cache,
-    #: frontier sampling, predict, scoring) — used for the stats counter.
-    EXACT_DISPATCHES_PER_ROUND = 5
+    #: jitted program launches of one exact-path round (its ``_round_seq``)
+    #: — used for the stats counter.
+    EXACT_DISPATCHES_PER_ROUND = 1
 
     def __init__(self, pool_icd, *, incremental: bool = True,
                  warm_start: bool | None = None, gp_steps: int = 150,
@@ -1231,6 +1234,7 @@ class BOEngine(_EngineBase):
         self._eval_mask = jnp.zeros((self.N,), bool)
         self._state: EngineState | None = None
         self._last_params: GPParams | None = None   # exact-path warm start
+        self._cold_params: dict | None = None   # host _default_params
         self._P = 0                              # current padded train size
         self._n_at_last_select = 0
         self._last_batch = None                  # (rows_pad, y_pad, mask)
@@ -1375,31 +1379,61 @@ class BOEngine(_EngineBase):
         return picks
 
     def _select_exact(self, key, sub_rows) -> int:
-        """The historical from-scratch round, call-for-call (bit-exact)."""
-        rows = np.asarray(self._rows)
-        P = len(rows) + (-len(rows)) % self.bucket
+        """The from-scratch round as one ``_round_seq`` dispatch.
+
+        With the static ``s0=0`` the round program always refactors and
+        builds V fresh, so a fresh state in and the pick out is the seed
+        loop's round: a ``gp_steps`` Adam fit from ``_default_params`` (or
+        the last round's params under ``warm_start``), the full
+        factorization, the per-objective frontier draw and the masked MES
+        argmax (first index wins). The host pads the batch in NumPy, so no
+        program runs per training-set size. Only the fitted params are kept
+        (the warm start), never a factorization."""
+        n = len(self._rows)
+        P = n + (-n) % self.bucket
         with span("engine.select", path="exact", P=P):
-            with span("engine.fit"):
-                x_train = self.pool[rows]
-                state = fit_gp(x_train, jnp.asarray(-self._y, jnp.float32),
-                               steps=self.gp_steps,
-                               params=(self._last_params if self.warm_start
-                                       else None),
-                               bucket=self.bucket)
+            with span("engine.pack"):
+                rows_pad, y_pad, mask = self._padded_batch(self._rows,
+                                                           self._y, P)
+                sub = (np.arange(self.N, dtype=np.int32) if sub_rows is None
+                       else np.asarray(sub_rows, np.int32))
+                weights = (np.ones((self.m,), np.float32)
+                           if self.weights is None else self.weights)
+                state = self._exact_state(P)
+                evalm = self._evalm_chunks()
+            with span("engine.launch"):
+                fused = resolve_round_backend("auto", self.N) == "pallas"
+                state, nxt, _, _, _ = _round_seq(
+                    state, rows_pad, y_pad, mask, self._pool_c, evalm,
+                    self._base, sub, key, True, self.drift_tol, weights,
+                    steps=self.gp_steps, s=self.s_frontiers, s0=0,
+                    select=True, fused=fused)
             self._last_params = state.params
-            with span("engine.score"):
-                fc = (self.pool if sub_rows is None
-                      else self.pool[np.asarray(sub_rows)])
-                scores = imoo_scores(state, self.pool, key,
-                                     s=self.s_frontiers, frontier_cand=fc,
-                                     weights=self.weights)
             with span("engine.fetch"):
-                scores = np.array(scores)
-            scores[rows] = -np.inf  # never re-evaluate
+                nxt = int(nxt)
             self.stats.rounds += 1
             self.stats.dispatches += self.EXACT_DISPATCHES_PER_ROUND
-            self._n_at_last_select = len(self._rows)
-            return int(np.argmax(scores))
+            self._n_at_last_select = n
+            return nxt
+
+    def _exact_state(self, P: int) -> EngineState:
+        """A fresh round state for the exact path. Cold params come from a
+        host copy of ``_default_params``, so they cost no device op; L and
+        V are zeros that ``s0=0`` never reads. ``params_ref`` is a second
+        copy because ``_round_seq`` donates every leaf."""
+        m = self.m
+        if self.warm_start and self._last_params is not None:
+            params = self._last_params
+            ref = jax.tree.map(lambda a: jnp.array(a, copy=True), params)
+        else:
+            if self._cold_params is None:
+                self._cold_params = _params_to_np(_default_params(m, self.d))
+            params, ref = (GPParams(**{k: v.copy() for k, v in
+                                       self._cold_params.items()})
+                           for _ in range(2))
+        return EngineState(
+            params, ref, jnp.zeros((m, P, P), jnp.float32),
+            jnp.zeros((self._nc, m, P, self._C), jnp.float32))
 
     def _select_incremental(self, key, sub_rows, *, reserve: int = 0,
                             do_select: bool = True) -> int:

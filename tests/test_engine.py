@@ -145,6 +145,58 @@ def test_fleet_incremental_runs_and_shares_cache(space, small_pool):
     assert st["refactors"] + st["block_updates"] == 4
 
 
+def test_exact_round_is_one_dispatch():
+    """An exact round counts one program launch and no factorization
+    decision: refactors and block updates are the incremental policy's."""
+    rng = np.random.default_rng(0)
+    pool = rng.normal(size=(40, 5)).astype(np.float32)
+    eng = BOEngine(pool, incremental=False, gp_steps=5, s_frontiers=4)
+    eng.observe(list(range(6)), rng.normal(size=(6, 3)))
+    for r in range(2):
+        before = eng.stats.as_dict()
+        nxt = eng.select(jax.random.PRNGKey(r))
+        after = eng.stats.as_dict()
+        assert nxt not in eng._rows and 0 <= nxt < 40
+        assert after["rounds"] - before["rounds"] == 1
+        assert after["dispatches"] - before["dispatches"] == 1
+        assert after["refactors"] == after["block_updates"] == 0
+        eng.observe([nxt], rng.normal(size=(1, 3)))
+
+
+def test_exact_round_runs_no_program_per_training_size(monkeypatch):
+    """The exact round launches ``_round_seq`` once, at the static s0=0,
+    and a round at a new training size inside the same 8-row bucket traces
+    nothing: no eager program is shaped by the training-set size."""
+    import repro.core.engine as engine_mod
+
+    rng = np.random.default_rng(1)
+    pool = rng.normal(size=(53, 7)).astype(np.float32)  # shapes of its own
+    eng = BOEngine(pool, incremental=False, gp_steps=3, s_frontiers=3)
+    eng.observe(list(range(9)), rng.normal(size=(9, 2)))
+    nxt = eng.select(jax.random.PRNGKey(0))          # n=9: compiles P=16
+    eng.observe([nxt], rng.normal(size=(1, 2)))
+
+    launches, traces = [], []
+    real = engine_mod._round_seq
+
+    def counted(*args, **kw):
+        launches.append(kw)
+        return real(*args, **kw)
+
+    def listener(name, secs, **kw):
+        if name == "/jax/core/compile/jaxpr_trace_duration":
+            traces.append(name)
+
+    monkeypatch.setattr(engine_mod, "_round_seq", counted)
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        eng.select(jax.random.PRNGKey(1))            # n=10: same bucket
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    assert [(k["s0"], k["select"]) for k in launches] == [(0, True)]
+    assert traces == []
+
+
 def test_engine_padding_matches_pad_training():
     """The engine's device-side padding (row indices + in-dispatch +10 shift)
     reproduces gp.pad_training exactly — the block-update prefix assumption

@@ -31,7 +31,7 @@ KW = dict(T=T, n=10, b=6, gp_steps=10, frontier_subset=64,
 
 #: the children each engine path's ``engine.select`` records, in order
 SELECT_CHILDREN = {
-    "exact": ["engine.fit", "engine.score", "engine.fetch"],
+    "exact": ["engine.pack", "engine.launch", "engine.fetch"],
     "incremental": ["engine.pack", "engine.launch", "engine.fetch"],
 }
 
